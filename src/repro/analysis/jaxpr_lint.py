@@ -36,7 +36,7 @@ _PALLAS_PRIMITIVES = frozenset({"pallas_call"})
 def _sub_jaxprs(params: dict) -> Iterator[Any]:
     """Every Jaxpr/ClosedJaxpr hiding in an equation's params (pjit
     call_jaxpr, shard_map jaxpr, scan/while bodies, cond branches, ...)."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def rec(v):
         if isinstance(v, ClosedJaxpr):

@@ -249,7 +249,18 @@ def packed_robust_sync(
     the SEED program: bit-exact outputs and byte-identical collective
     budgets, machine-checked by the ``sync_telemetry_off_*`` analysis
     target. The ``jax.named_scope`` phase markers are always on — they
-    annotate HLO metadata only and add zero operations."""
+    annotate HLO metadata only and add zero operations.
+
+    The sync's matmuls run at ``highest`` precision: on a TPU the default
+    rounds fp32 operands to bf16, and the Gram-space coefficients are
+    differences of Gram entries that bf16 rounding moves by ~1e-3."""
+    with jax.default_matmul_precision("highest"):
+        return _packed_robust_sync(grads_w, aggregator, key, mesh, block_d,
+                                   use_kernels, out_shardings, telemetry)
+
+
+def _packed_robust_sync(grads_w, aggregator, key, mesh, block_d,
+                        use_kernels, out_shardings, telemetry):
     packer = packer_for(grads_w, block_d=block_d)
     leaves = jax.tree_util.tree_leaves(grads_w)
     W = leaves[0].shape[0]

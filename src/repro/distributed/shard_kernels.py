@@ -36,7 +36,6 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops
@@ -79,8 +78,8 @@ def gram(buf: jnp.ndarray, mesh, *, block_d: int = 2048) -> jnp.ndarray:
     ax = _axes(mesh)
     buf, _ = _pad_cols(buf, mesh)
     body = lambda b: jax.lax.psum(ops.gram(b, block_d=block_d), ax)
-    return shard_map(body, mesh=mesh, in_specs=(col_spec(mesh),),
-                     out_specs=P(), check_rep=False)(buf)
+    return jax.shard_map(body, mesh=mesh, in_specs=(col_spec(mesh),),
+                     out_specs=P(), check_vma=False)(buf)
 
 
 def mix_apply(mix: jnp.ndarray, buf: jnp.ndarray, mesh, *,
@@ -90,8 +89,8 @@ def mix_apply(mix: jnp.ndarray, buf: jnp.ndarray, mesh, *,
     column-sharded."""
     buf, n = _pad_cols(buf, mesh)
     body = lambda m, b: ops.mix_apply(m, b, block_d=block_d)
-    out = shard_map(body, mesh=mesh, in_specs=(P(None, None), col_spec(mesh)),
-                    out_specs=col_spec(mesh), check_rep=False)(mix, buf)
+    out = jax.shard_map(body, mesh=mesh, in_specs=(P(None, None), col_spec(mesh)),
+                    out_specs=col_spec(mesh), check_vma=False)(mix, buf)
     return out[:, :n] if n != out.shape[1] else out
 
 
@@ -100,8 +99,8 @@ def cm_aggregate(buf: jnp.ndarray, mesh, *, block_d: int = 4096) -> jnp.ndarray:
     device; output is the column-sharded ``[n]`` aggregate."""
     buf, n = _pad_cols(buf, mesh)
     body = lambda b: ops.cm_aggregate(b, block_d=block_d)
-    out = shard_map(body, mesh=mesh, in_specs=(col_spec(mesh),),
-                    out_specs=vec_spec(mesh), check_rep=False)(buf)
+    out = jax.shard_map(body, mesh=mesh, in_specs=(col_spec(mesh),),
+                    out_specs=vec_spec(mesh), check_vma=False)(buf)
     return out[:n] if n != out.shape[0] else out
 
 
@@ -111,8 +110,8 @@ def tm_aggregate(buf: jnp.ndarray, n_trim: int, mesh, *,
     per device; output is the column-sharded ``[n]`` aggregate."""
     buf, n = _pad_cols(buf, mesh)
     body = lambda b: ops.tm_aggregate(b, n_trim, block_d=block_d)
-    out = shard_map(body, mesh=mesh, in_specs=(col_spec(mesh),),
-                    out_specs=vec_spec(mesh), check_rep=False)(buf)
+    out = jax.shard_map(body, mesh=mesh, in_specs=(col_spec(mesh),),
+                    out_specs=vec_spec(mesh), check_vma=False)(buf)
     return out[:n] if n != out.shape[0] else out
 
 
@@ -121,8 +120,8 @@ def coordinatewise_combine(buf: jnp.ndarray, mesh,
     """Any column-local ``[W, n] -> [n]`` reduction (an aggregator's
     ``combine_leaf`` — trimmed mean etc.) run per column shard."""
     buf, n = _pad_cols(buf, mesh)
-    out = shard_map(combine_fn, mesh=mesh, in_specs=(col_spec(mesh),),
-                    out_specs=vec_spec(mesh), check_rep=False)(buf)
+    out = jax.shard_map(combine_fn, mesh=mesh, in_specs=(col_spec(mesh),),
+                    out_specs=vec_spec(mesh), check_vma=False)(buf)
     return out[:n] if n != out.shape[0] else out
 
 
@@ -139,14 +138,14 @@ def residual_norms(buf: jnp.ndarray, coeffs: Optional[jnp.ndarray] = None, *,
     buf, _ = _pad_cols(buf, mesh)
     if coeffs is not None:
         body = lambda b, c: jax.lax.psum(ops.norms(b, c, block_d=block_d), ax)
-        return shard_map(body, mesh=mesh, in_specs=(col_spec(mesh), P(None)),
-                         out_specs=P(), check_rep=False)(buf, coeffs)
+        return jax.shard_map(body, mesh=mesh, in_specs=(col_spec(mesh), P(None)),
+                         out_specs=P(), check_vma=False)(buf, coeffs)
     center, _ = _pad_cols(center, mesh)
     body = lambda b, v: jax.lax.psum(
         ops.norms(b, center=v, block_d=block_d), ax)
-    return shard_map(body, mesh=mesh,
+    return jax.shard_map(body, mesh=mesh,
                      in_specs=(col_spec(mesh), vec_spec(mesh)),
-                     out_specs=P(), check_rep=False)(buf, center)
+                     out_specs=P(), check_vma=False)(buf, center)
 
 
 def cclip_fused_iter(buf: jnp.ndarray, v: jnp.ndarray, lam: jnp.ndarray,
@@ -163,10 +162,10 @@ def cclip_fused_iter(buf: jnp.ndarray, v: jnp.ndarray, lam: jnp.ndarray,
         v_new, r2 = ops.cclip_iter(b, vv, ll, block_d=block_d)
         return v_new, jax.lax.psum(r2, ax)
 
-    v_new, r2 = shard_map(
+    v_new, r2 = jax.shard_map(
         body, mesh=mesh,
         in_specs=(col_spec(mesh), vec_spec(mesh), P(None)),
-        out_specs=(vec_spec(mesh), P()), check_rep=False)(buf, v, lam)
+        out_specs=(vec_spec(mesh), P()), check_vma=False)(buf, v, lam)
     return (v_new[:n] if n != v_new.shape[0] else v_new), r2
 
 

@@ -4,11 +4,13 @@ The paper's server-side cost is dominated by streaming the ``[W, d]``
 stacked worker gradients (d up to 10^12 / n_chips): the Gram stats phase
 (Krum/RFA/CCLIP), the coordinate-wise median, the Weiszfeld/CCLIP inner
 iterations, and the Algorithm-1 mixing itself. Each is a one- or two-pass
-streaming kernel with explicit BlockSpec VMEM tiling; pure-jnp oracles live
-in ``ref.py`` and the jit'd public API in ``ops.py``.
+streaming kernel with explicit BlockSpec VMEM tiling; pure-jnp test
+oracles live in ``ref.py`` and the jit'd public API in ``ops.py``.
 
-Validated with ``interpret=True`` on CPU (Mosaic does not lower on the CPU
-backend); on TPU the identical ``pl.pallas_call``s compile natively.
+Each kernel's ``interpret=None`` resolves through ``ops._interp``: the
+Pallas interpreter on CPU (Mosaic does not lower there), Mosaic on a TPU.
+Every contraction asks for ``Precision.HIGHEST``: Mosaic's default rounds
+fp32 operands to bf16, which is visible in an fp32 aggregate.
 """
 
 from repro.kernels.bucket_mix import bucket_mix

@@ -20,14 +20,18 @@ def _mix_kernel(m_ref, x_ref, out_ref):
     m = m_ref[...].astype(jnp.float32)
     x = x_ref[...].astype(jnp.float32)
     out_ref[...] = jax.lax.dot_general(
-        m, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        m, x, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32
     )
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def bucket_mix(mix: jnp.ndarray, xs: jnp.ndarray, *, block_d: int = 2048,
-               interpret: bool = True):
+               interpret: bool | None = None):
     """mix: [m, W] row-stochastic; xs: [W, d] -> mixed [m, d] fp32."""
+    from repro.kernels.ops import _interp  # ops imports this module
+
+    interpret = _interp(interpret)
     m, W = mix.shape
     W2, d = xs.shape
     assert W == W2, (mix.shape, xs.shape)

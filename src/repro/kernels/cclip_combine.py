@@ -24,15 +24,19 @@ def _combine_kernel(lam_ref, v_ref, x_ref, out_ref, *, W: int):
     v = v_ref[...].astype(jnp.float32)          # [1, bd]
     x = x_ref[...].astype(jnp.float32)          # [Wp, bd]
     upd = jax.lax.dot_general(                  # [1, bd] = lam @ (x - v)
-        lam, x - v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        lam, x - v, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32
     )
     out_ref[...] = v + upd / W
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def cclip_combine(xs: jnp.ndarray, v: jnp.ndarray, lam: jnp.ndarray, *,
-                  block_d: int = 2048, interpret: bool = True):
+                  block_d: int = 2048, interpret: bool | None = None):
     """xs: [W, d]; v: [d]; lam: [W] -> updated center [d] fp32."""
+    from repro.kernels.ops import _interp  # ops imports this module
+
+    interpret = _interp(interpret)
     W, d = xs.shape
     Wp = max(8, -(-W // 8) * 8)
     bd = min(block_d, max(128, -(-d // 128) * 128))

@@ -38,7 +38,8 @@ def _fused_kernel(lam_ref, v_ref, x_ref, vout_ref, r2_ref, *, W: int):
     v = v_ref[...].astype(jnp.float32)          # [1, bd]
     x = x_ref[...].astype(jnp.float32)          # [Wp, bd]
     upd = jax.lax.dot_general(                  # [1, bd] = lam @ (x - v)
-        lam, x - v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        lam, x - v, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32
     )
     v_new = v + upd / W
     vout_ref[...] = v_new
@@ -48,8 +49,11 @@ def _fused_kernel(lam_ref, v_ref, x_ref, vout_ref, r2_ref, *, W: int):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def cclip_fused_iter(xs: jnp.ndarray, v: jnp.ndarray, lam: jnp.ndarray, *,
-                     block_d: int = 2048, interpret: bool = True):
+                     block_d: int = 2048, interpret: bool | None = None):
     """xs: [W, d]; v: [d]; lam: [W] -> (v' [d] fp32, ||x_i - v'||^2 [W] fp32)."""
+    from repro.kernels.ops import _interp  # ops imports this module
+
+    interpret = _interp(interpret)
     W, d = xs.shape
     Wp = max(8, -(-W // 8) * 8)
     bd = min(block_d, max(128, -(-d // 128) * 128))

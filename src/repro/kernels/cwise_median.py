@@ -44,8 +44,12 @@ def _median_kernel(x_ref, out_ref, *, W: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
-def cwise_median(xs: jnp.ndarray, *, block_d: int = 4096, interpret: bool = True):
+def cwise_median(xs: jnp.ndarray, *, block_d: int = 4096,
+                 interpret: bool | None = None):
     """xs: [W, d] -> median over workers [d] fp32."""
+    from repro.kernels.ops import _interp  # ops imports this module
+
+    interpret = _interp(interpret)
     W, d = xs.shape
     Wp = max(8, -(-W // 8) * 8)
     if interpret:

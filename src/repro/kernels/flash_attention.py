@@ -81,11 +81,14 @@ def flash_attention(
     block_q: int = 128,
     block_kv: int = 128,
     q_offset: int = -1,  # -1 => Skv - Sq (decode-style suffix alignment)
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Returns [B, Sq, H, dh]. GQA: each query head h reads kv head
     h // (H // KV). Sq must be divisible by block_q and Skv by block_kv
     (callers pick divisor blocks; see models.attention._divisor_block)."""
+    from repro.kernels.ops import _interp  # ops imports this module
+
+    interpret = _interp(interpret)
     B, Sq, H, dh = q.shape
     Skv, KV = k.shape[1], k.shape[2]
     assert Sq % block_q == 0 and Skv % block_kv == 0, (Sq, block_q, Skv, block_kv)

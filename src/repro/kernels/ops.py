@@ -1,8 +1,9 @@
 """jit'd public wrappers over the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (the kernels execute their bodies in
-Python/XLA on CPU — this is how the container validates them); on a real TPU
-backend the same calls lower through Mosaic.
+Interpret mode is chosen in one place, ``_interp``: every kernel's
+``interpret=None`` default resolves through it to Mosaic on a TPU backend
+and to the Pallas interpreter on any other (how the CPU tests run the
+kernel bodies). An explicit ``interpret=False`` always asks for Mosaic.
 
 The composed aggregators here are the kernel-accelerated counterparts of
 ``repro.core.aggregators`` (oracles in ``ref.py``; equivalence is asserted
@@ -47,39 +48,41 @@ from repro.kernels.trimmed_mean import cwise_trimmed_mean
 from repro.kernels.weiszfeld_norms import residual_norms
 
 
-def _interp() -> bool:
-    return jax.default_backend() != "tpu"
+def _interp(interpret: bool | None = None) -> bool:
+    """The interpret-mode rule: an explicit bool is kept; ``None`` means
+    interpret on every backend but the TPU."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
 
 def gram(xs: jnp.ndarray, acc: jnp.ndarray | None = None, *,
          block_d: int = 2048, full_blocks: bool = False) -> jnp.ndarray:
-    return pairwise_gram(xs, acc, block_d=block_d, full_blocks=full_blocks,
-                         interpret=_interp())
+    return pairwise_gram(xs, acc, block_d=block_d, full_blocks=full_blocks)
 
 
 def cm_aggregate(xs: jnp.ndarray, *, block_d: int = 4096) -> jnp.ndarray:
-    return cwise_median(xs, block_d=block_d, interpret=_interp())
+    return cwise_median(xs, block_d=block_d)
 
 
 def tm_aggregate(xs: jnp.ndarray, n_trim: int, *, block_d: int = 4096) -> jnp.ndarray:
-    return cwise_trimmed_mean(xs, n_trim, block_d=block_d, interpret=_interp())
+    return cwise_trimmed_mean(xs, n_trim, block_d=block_d)
 
 
 def mix_apply(mix: jnp.ndarray, xs: jnp.ndarray, *, block_d: int = 2048) -> jnp.ndarray:
-    return bucket_mix(mix, xs, block_d=block_d, interpret=_interp())
+    return bucket_mix(mix, xs, block_d=block_d)
 
 
 def norms(xs: jnp.ndarray, coeffs: jnp.ndarray | None = None, *,
           center: jnp.ndarray | None = None, block_d: int = 2048) -> jnp.ndarray:
     """Residual sq-norms ``||x_i - v||^2`` with v as coeffs or explicit row."""
-    return residual_norms(xs, coeffs, center=center, block_d=block_d,
-                          interpret=_interp())
+    return residual_norms(xs, coeffs, center=center, block_d=block_d)
 
 
 def cclip_iter(xs: jnp.ndarray, v: jnp.ndarray, lam: jnp.ndarray, *,
                block_d: int = 2048):
     """One fused CCLIP iteration -> ``(v', ||x_i - v'||^2)``."""
-    return cclip_fused_iter(xs, v, lam, block_d=block_d, interpret=_interp())
+    return cclip_fused_iter(xs, v, lam, block_d=block_d)
 
 
 @functools.partial(jax.jit, static_argnames=("n_iters", "block_d"))
@@ -87,10 +90,9 @@ def rfa_aggregate(xs: jnp.ndarray, *, n_iters: int = 8, eps: float = 1e-6,
                   block_d: int = 2048) -> jnp.ndarray:
     """Geometric median of worker rows via kernel-fused Weiszfeld."""
     W = xs.shape[0]
-    interp = _interp()
 
     def body(c, _):
-        r2 = residual_norms(xs, c, block_d=block_d, interpret=interp)
+        r2 = residual_norms(xs, c, block_d=block_d)
         w = 1.0 / jnp.sqrt(r2 + eps**2)
         return w / jnp.sum(w), None
 
@@ -110,14 +112,13 @@ def cclip_aggregate(xs: jnp.ndarray, tau: float, *, n_iters: int = 3,
     pass (with an explicit center row; no pseudo-row concat).
     """
     W = xs.shape[0]
-    interp = _interp()
     v = mix_apply(jnp.full((1, W), 1.0 / W, jnp.float32), xs, block_d=block_d)[0]
-    r2 = residual_norms(xs, center=v, block_d=block_d, interpret=interp)
+    r2 = residual_norms(xs, center=v, block_d=block_d)
 
     def body(carry, _):
         v, r2 = carry
         lam = jnp.minimum(1.0, tau / jnp.sqrt(r2 + eps))
-        return cclip_fused_iter(xs, v, lam, block_d=block_d, interpret=interp), None
+        return cclip_fused_iter(xs, v, lam, block_d=block_d), None
 
     (v, _), _ = jax.lax.scan(body, (v, r2), None, length=n_iters)
     return v
@@ -130,17 +131,16 @@ def cclip_aggregate_unfused(xs: jnp.ndarray, tau: float, *, n_iters: int = 3,
     with the center appended to the stack as a pseudo-row (a full stack
     copy). Kept as the microbenchmark baseline for ``cclip_aggregate``."""
     W = xs.shape[0]
-    interp = _interp()
     v = mix_apply(jnp.full((1, W), 1.0 / W, jnp.float32), xs, block_d=block_d)[0]
 
     def body(v, _):
         diffs2 = residual_norms(
             jnp.concatenate([xs.astype(jnp.float32), v[None, :]], axis=0),
             jnp.zeros((W + 1,), jnp.float32).at[W].set(1.0),
-            block_d=block_d, interpret=interp,
+            block_d=block_d,
         )[:W]
         lam = jnp.minimum(1.0, tau / jnp.sqrt(diffs2 + eps))
-        v_new = cclip_combine(xs, v, lam, block_d=block_d, interpret=interp)
+        v_new = cclip_combine(xs, v, lam, block_d=block_d)
         return v_new, None
 
     v, _ = jax.lax.scan(body, v, None, length=n_iters)

@@ -39,13 +39,14 @@ def _gram_kernel(acc_ref, x_ref, out_ref):
 
     x = x_ref[...].astype(jnp.float32)
     out_ref[...] += jax.lax.dot_general(
-        x, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, x, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32
     )
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret", "full_blocks"))
 def pairwise_gram(xs: jnp.ndarray, acc: jnp.ndarray | None = None, *,
-                  block_d: int = 2048, interpret: bool = True,
+                  block_d: int = 2048, interpret: bool | None = None,
                   full_blocks: bool = False):
     """xs: [W, d] (any float dtype) -> Gram [W, W] fp32 (``acc +`` if given).
 
@@ -54,6 +55,9 @@ def pairwise_gram(xs: jnp.ndarray, acc: jnp.ndarray | None = None, *,
     ``full_blocks`` forces the block width to exactly ``block_d`` (padding d
     up to a ``block_d`` multiple) so block shapes are independent of ``d``.
     """
+    from repro.kernels.ops import _interp  # ops imports this module
+
+    interpret = _interp(interpret)
     W, d = xs.shape
     Wp = max(8, -(-W // 8) * 8)
     if full_blocks:

@@ -2,8 +2,8 @@
 
 Each function is the semantic ground truth the kernels are validated against
 (tests/test_kernels.py sweeps shapes/dtypes and asserts allclose in
-interpret mode). They are also the CPU fallback used by ``ops.py`` when the
-backend cannot lower Pallas.
+interpret mode). They are test oracles only: no code path falls back to
+them, on any backend.
 """
 
 from __future__ import annotations

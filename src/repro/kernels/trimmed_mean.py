@@ -41,10 +41,13 @@ def _tm_kernel(x_ref, out_ref, *, W: int, n_trim: int):
 
 @functools.partial(jax.jit, static_argnames=("n_trim", "block_d", "interpret"))
 def cwise_trimmed_mean(xs: jnp.ndarray, n_trim: int, *, block_d: int = 4096,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """xs: [W, d] -> mean of the sorted [n_trim, W-n_trim) worker band, [d]
     fp32. ``n_trim`` must satisfy ``0 <= n_trim <= (W - 1) // 2`` (callers
     clamp; asserted here because the band must be non-empty)."""
+    from repro.kernels.ops import _interp  # ops imports this module
+
+    interpret = _interp(interpret)
     W, d = xs.shape
     if not 0 <= n_trim <= (W - 1) // 2:
         raise ValueError(f"n_trim={n_trim} out of range for W={W}")

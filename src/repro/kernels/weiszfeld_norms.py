@@ -37,7 +37,8 @@ def _resid_kernel(c_ref, x_ref, out_ref):
     x = x_ref[...].astype(jnp.float32)          # [Wp, bd]
     c = c_ref[...].astype(jnp.float32)          # [1, Wp]
     v = jax.lax.dot_general(                    # [1, bd]
-        c, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        c, x, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32
     )
     diff = x - v
     out_ref[...] += jnp.sum(diff * diff, axis=1, keepdims=True).T  # [1, Wp]
@@ -67,10 +68,13 @@ def _pad_dims(W, d, block_d):
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def residual_norms(xs: jnp.ndarray, coeffs: jnp.ndarray | None = None, *,
                    center: jnp.ndarray | None = None, block_d: int = 2048,
-                   interpret: bool = True):
+                   interpret: bool | None = None):
     """xs: [W, d] -> residual sq norms [W] fp32 against the center given
     either as ``coeffs: [W]`` (``v = coeffs^T xs``) or as an explicit
     ``center: [d]`` row. Exactly one of the two must be provided."""
+    from repro.kernels.ops import _interp  # ops imports this module
+
+    interpret = _interp(interpret)
     if (coeffs is None) == (center is None):
         raise ValueError("provide exactly one of coeffs / center")
     W, d = xs.shape

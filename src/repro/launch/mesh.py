@@ -10,18 +10,27 @@ sets XLA_FLAGS for 512 placeholder devices before any jax import.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    # jax.make_mesh defaults to Explicit axes; the sync's reshards
+    # (packing.reshard_in / reshard_out / unpack_to_shardings) use
+    # with_sharding_constraint, which only accepts Auto axes.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over however many (host/CPU) devices exist — used by
-    sharding-semantics tests with xla_force_host_platform_device_count."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """``(data, model)`` mesh over the first ``data * model`` devices: the
+    trainer's mesh on one chip (1, 1) or a four-chip host (4, 1), and the
+    sharding tests' mesh on forced host devices."""
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def worker_axes(mesh) -> tuple:

@@ -53,7 +53,10 @@ def _segsum_exp(da: jnp.ndarray) -> jnp.ndarray:
     diff = cs[..., :, None] - cs[..., None, :]
     L = da.shape[-1]
     tri = jnp.tril(jnp.ones((L, L), bool))
-    return jnp.where(tri, jnp.exp(diff), 0.0)
+    # Mask before the exp: above the diagonal diff > 0 overflows once a
+    # chunk's decay passes ~88, and where(tri, exp(diff), 0) then
+    # back-propagates 0 * inf = NaN into dt and A.
+    return jnp.exp(jnp.where(tri, diff, -jnp.inf))
 
 
 def _causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
