@@ -10,7 +10,13 @@ leaves the architecture has — see repro/distributed/packing.py). Attack simula
 single-host simulation path (repro/training/byzantine.py); the distributed
 path runs the defense.
 
-Momentum modes (DESIGN.md §5):
+The step's phases carry name scopes (``telemetry.phase(..., layer="step")``):
+``step/grad`` (per-worker forward and backward; backward operations show
+``transpose(`` in their ``op_name``), ``step/momentum``, ``step/sync`` (the
+sync's own ``telemetry/*`` phases nest inside) and ``step/optimizer``. They
+touch only HLO metadata; a profiler trace reads device time through them.
+
+Momentum modes:
   worker : Algorithm 2 — per-worker momentum leaves [W, ...] (small/mid archs)
   server : Remark 7 — raw per-worker grads robust-aggregated, momentum in
            the (shardable) optimizer state (giant archs / FSDP).
@@ -39,6 +45,7 @@ from repro.distributed.sharding import (
 from repro.launch.mesh import n_workers as mesh_n_workers
 from repro.models import transformer as tfm
 from repro.optim import make_optimizer
+from repro.telemetry import phase
 
 
 # ------------------------------------------------------------- input specs
@@ -136,7 +143,8 @@ def make_train_step(
                 loss, aux = jax.vmap(lambda b: loss_of(p, b))(wbatch)
                 return jnp.mean(loss), aux
 
-            (loss, aux), grads = jax.value_and_grad(mean_loss, has_aux=True)(params)
+            with phase("grad", layer="step"):
+                (loss, aux), grads = jax.value_and_grad(mean_loss, has_aux=True)(params)
             agg_grads = grads
             info = {}
         else:
@@ -144,24 +152,28 @@ def make_train_step(
                 (loss, aux), g = jax.value_and_grad(loss_of, has_aux=True)(params, b)
                 return g, loss
 
-            grads_w, losses = jax.vmap(one_worker)(wbatch)
-            loss = jnp.mean(losses)
+            with phase("grad", layer="step"):
+                grads_w, losses = jax.vmap(one_worker)(wbatch)
+                loss = jnp.mean(losses)
             if use_worker_momentum:
                 beta = byz.worker_momentum
-                worker_m = jax.tree_util.tree_map(
-                    lambda m, g: beta * m + (1.0 - beta) * g.astype(jnp.float32),
-                    worker_m,
-                    grads_w,
-                )
+                with phase("momentum", layer="step"):
+                    worker_m = jax.tree_util.tree_map(
+                        lambda m, g: beta * m + (1.0 - beta) * g.astype(jnp.float32),
+                        worker_m,
+                        grads_w,
+                    )
                 messages = worker_m
             else:
                 messages = grads_w
-            agg_grads, info = robust_gradient_sync(
-                messages, aggregator, key=key, mesh=mesh, engine="packed",
-                out_shardings=egress_sh, telemetry=telemetry,
-            )
+            with phase("sync", layer="step"):
+                agg_grads, info = robust_gradient_sync(
+                    messages, aggregator, key=key, mesh=mesh, engine="packed",
+                    out_shardings=egress_sh, telemetry=telemetry,
+                )
 
-        params, opt_state = opt_update(agg_grads, opt_state, params)
+        with phase("optimizer", layer="step"):
+            params, opt_state = opt_update(agg_grads, opt_state, params)
         metrics = {"loss": loss}
         if telemetry and "telemetry" in info:
             metrics["telemetry"] = info["telemetry"]
@@ -211,8 +223,7 @@ def make_prefill_step(cfg: ModelConfig, mesh, last_only: bool = True) -> Callabl
     """Serving prefill. ``last_only`` (default) unembeds ONLY the final
     position — the next-token logits a server actually needs. Materializing
     full-sequence fp32 logits is a [B, S, V] tensor (67 GB/device for
-    gemma-7b at prefill_32k) that dominated peak memory; see EXPERIMENTS.md
-    §Perf iteration 2."""
+    gemma-7b at prefill_32k) that dominated peak memory."""
 
     def prefill(params, batch):
         h, _ = tfm.forward_hidden(

@@ -53,5 +53,6 @@ def bucket_mix(mix: jnp.ndarray, xs: jnp.ndarray, *, block_d: int = 2048,
         out_specs=pl.BlockSpec((mp, bd), lambda k: (0, k)),
         out_shape=jax.ShapeDtypeStruct((mp, dp), jnp.float32),
         interpret=interpret,
+        name="bucket_mix",
     )(mx, x)
     return out[:m, :d]

@@ -57,5 +57,6 @@ def cclip_combine(xs: jnp.ndarray, v: jnp.ndarray, lam: jnp.ndarray, *,
         out_specs=pl.BlockSpec((1, bd), lambda k: (0, k)),
         out_shape=jax.ShapeDtypeStruct((1, dp), jnp.float32),
         interpret=interpret,
+        name="cclip_combine",
     )(lm, vp, x)
     return out[0, :d]

@@ -80,5 +80,6 @@ def cclip_fused_iter(xs: jnp.ndarray, v: jnp.ndarray, lam: jnp.ndarray, *,
             jax.ShapeDtypeStruct((1, Wp), jnp.float32),
         ],
         interpret=interpret,
+        name="cclip_fused_iter",
     )(lm, vp, x)
     return v_new[0, :d], r2[0, :W]

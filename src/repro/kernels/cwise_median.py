@@ -71,5 +71,6 @@ def cwise_median(xs: jnp.ndarray, *, block_d: int = 4096,
         out_specs=pl.BlockSpec((1, bd), lambda k: (0, k)),
         out_shape=jax.ShapeDtypeStruct((1, dp), jnp.float32),
         interpret=interpret,
+        name="cwise_median",
     )(x)
     return out[0, :d]

@@ -119,5 +119,6 @@ def flash_attention(
                                lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, dh), q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
     return jnp.moveaxis(out, 1, 2)

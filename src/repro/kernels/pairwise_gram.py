@@ -1,6 +1,6 @@
 """Pallas TPU kernel: worker Gram matrix ``G[i,j] = <x_i, x_j>``.
 
-The stats phase of Krum / RFA / CCLIP (DESIGN.md §4) is a rank-``d``
+The stats phase of Krum / RFA / CCLIP is a rank-``d``
 reduction of outer products — a natural MXU workload. The parameter
 dimension is tiled into VMEM-resident ``[W, bd]`` blocks (``bd`` a multiple
 of 128 so the contraction dim is MXU-aligned); the tiny ``[W, W]`` fp32
@@ -81,5 +81,6 @@ def pairwise_gram(xs: jnp.ndarray, acc: jnp.ndarray | None = None, *,
         out_specs=pl.BlockSpec((Wp, Wp), lambda k: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((Wp, Wp), jnp.float32),
         interpret=interpret,
+        name="pairwise_gram",
     )(a, x)
     return out[:W, :W]

@@ -70,5 +70,6 @@ def cwise_trimmed_mean(xs: jnp.ndarray, n_trim: int, *, block_d: int = 4096,
         out_specs=pl.BlockSpec((1, bd), lambda k: (0, k)),
         out_shape=jax.ShapeDtypeStruct((1, dp), jnp.float32),
         interpret=interpret,
+        name="cwise_trimmed_mean",
     )(x)
     return out[0, :d]

@@ -102,5 +102,6 @@ def residual_norms(xs: jnp.ndarray, coeffs: jnp.ndarray | None = None, *,
         out_specs=pl.BlockSpec((1, Wp), lambda k: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, Wp), jnp.float32),
         interpret=interpret,
+        name="residual_norms",
     )(first, x)
     return out[0, :W]

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import dense_init, rmsnorm
+from repro.telemetry import phase
 
 
 # ------------------------------------------------------------------ params
@@ -65,7 +66,7 @@ def _causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     Deliberately NOT lax.conv_general_dilated(feature_group_count=C): XLA
     lowers that conv's filter gradient to a full cross-channel correlation
     (observed: f32[K, B*C, B*C] — 2.8e17 FLOPs for jamba train_4k, 200x the
-    whole model; see EXPERIMENTS.md §Perf iteration 1). K is 4: unrolled
+    whole model). K is 4: unrolled
     shift-and-add is exact, differentiates cleanly, and is a pure VPU
     (elementwise) workload on TPU — strictly better than a grouped conv.
     """
@@ -83,81 +84,92 @@ def _causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 # ------------------------------------------------------------------- train
 def ssd_scan(x, dt, A, B_, C_, chunk: int):
     """Chunked SSD. x: [B,S,H,P]; dt: [B,S,H]; A: [H] (negative);
-    B_, C_: [B,S,G,N] (G=1). Returns y: [B,S,H,P] and final state [B,H,P,N]."""
+    B_, C_: [B,S,G,N] (G=1). Returns y: [B,S,H,P] and final state [B,H,P,N].
+
+    Name scopes: ``ssd/chunk`` the intra-chunk dual form, ``ssd/state`` the
+    chunk-boundary states, the inter-chunk scan and their contribution."""
     Bsz, S, H, P = x.shape
     N = B_.shape[-1]
     Q = min(chunk, S)
     assert S % Q == 0, (S, Q)
     nc = S // Q
 
-    # broadcast groups (G=1) over heads
-    Bh = jnp.broadcast_to(B_[:, :, 0:1], (Bsz, S, 1, N))[:, :, 0]  # [B,S,N]
-    Ch = jnp.broadcast_to(C_[:, :, 0:1], (Bsz, S, 1, N))[:, :, 0]
+    with phase("chunk", layer="ssd"):
+        # broadcast groups (G=1) over heads
+        Bh = jnp.broadcast_to(B_[:, :, 0:1], (Bsz, S, 1, N))[:, :, 0]  # [B,S,N]
+        Ch = jnp.broadcast_to(C_[:, :, 0:1], (Bsz, S, 1, N))[:, :, 0]
 
-    xc = x.reshape(Bsz, nc, Q, H, P).astype(jnp.float32)
-    dtc = dt.reshape(Bsz, nc, Q, H).astype(jnp.float32)
-    Bc = Bh.reshape(Bsz, nc, Q, N).astype(jnp.float32)
-    Cc = Ch.reshape(Bsz, nc, Q, N).astype(jnp.float32)
+        xc = x.reshape(Bsz, nc, Q, H, P).astype(jnp.float32)
+        dtc = dt.reshape(Bsz, nc, Q, H).astype(jnp.float32)
+        Bc = Bh.reshape(Bsz, nc, Q, N).astype(jnp.float32)
+        Cc = Ch.reshape(Bsz, nc, Q, N).astype(jnp.float32)
 
-    da = dtc * A[None, None, None, :]  # [B,c,Q,H]
-    da_t = jnp.moveaxis(da, -1, -2)  # [B,c,H,Q]
-    cs = jnp.cumsum(da_t, axis=-1)  # [B,c,H,Q]
-    xdt = xc * dtc[..., None]  # input scaled by dt
+        da = dtc * A[None, None, None, :]  # [B,c,Q,H]
+        da_t = jnp.moveaxis(da, -1, -2)  # [B,c,H,Q]
+        cs = jnp.cumsum(da_t, axis=-1)  # [B,c,H,Q]
+        xdt = xc * dtc[..., None]  # input scaled by dt
 
-    # intra-chunk (quadratic/dual form)
-    Lm = _segsum_exp(da_t)  # [B,c,H,Q,Q]
-    scores = jnp.einsum("bcln,bcsn->bcls", Cc, Bc)  # [B,c,Q,Q]
-    y_diag = jnp.einsum("bcls,bchls,bcshp->bclhp", scores, Lm, xdt)
+        # intra-chunk (quadratic/dual form)
+        Lm = _segsum_exp(da_t)  # [B,c,H,Q,Q]
+        scores = jnp.einsum("bcln,bcsn->bcls", Cc, Bc)  # [B,c,Q,Q]
+        y_diag = jnp.einsum("bcls,bchls,bcshp->bclhp", scores, Lm, xdt)
 
-    # chunk-boundary states
-    decay_to_end = jnp.exp(cs[..., -1:] - cs)  # [B,c,H,Q]
-    states = jnp.einsum("bcsn,bchs,bcshp->bchpn", Bc, decay_to_end, xdt)
+    with phase("state", layer="ssd"):
+        # chunk-boundary states
+        decay_to_end = jnp.exp(cs[..., -1:] - cs)  # [B,c,H,Q]
+        states = jnp.einsum("bcsn,bchs,bcshp->bchpn", Bc, decay_to_end, xdt)
 
-    # inter-chunk recurrence
-    chunk_decay = jnp.exp(cs[..., -1])  # [B,c,H]
+        # inter-chunk recurrence
+        chunk_decay = jnp.exp(cs[..., -1])  # [B,c,H]
 
-    def step(h_prev, inp):
-        st, dec = inp  # [B,H,P,N], [B,H]
-        h_new = h_prev * dec[..., None, None] + st
-        return h_new, h_prev
+        def step(h_prev, inp):
+            st, dec = inp  # [B,H,P,N], [B,H]
+            h_new = h_prev * dec[..., None, None] + st
+            return h_new, h_prev
 
-    h0 = jnp.zeros((Bsz, H, P, N), jnp.float32)
-    h_last, h_prevs = jax.lax.scan(
-        step, h0, (jnp.moveaxis(states, 1, 0), jnp.moveaxis(chunk_decay, 1, 0))
-    )
-    h_prevs = jnp.moveaxis(h_prevs, 0, 1)  # [B,c,H,P,N], state entering chunk c
+        h0 = jnp.zeros((Bsz, H, P, N), jnp.float32)
+        h_last, h_prevs = jax.lax.scan(
+            step, h0, (jnp.moveaxis(states, 1, 0), jnp.moveaxis(chunk_decay, 1, 0))
+        )
+        h_prevs = jnp.moveaxis(h_prevs, 0, 1)  # [B,c,H,P,N], state entering chunk c
 
-    # contribution of carried-in state
-    decay_in = jnp.exp(cs)  # [B,c,H,Q]
-    y_off = jnp.einsum("bcln,bchpn,bchl->bclhp", Cc, h_prevs, decay_in)
+        # contribution of carried-in state
+        decay_in = jnp.exp(cs)  # [B,c,H,Q]
+        y_off = jnp.einsum("bcln,bchpn,bchl->bclhp", Cc, h_prevs, decay_in)
 
-    y = (y_diag + y_off).reshape(Bsz, S, H, P)
+        y = (y_diag + y_off).reshape(Bsz, S, H, P)
     return y, h_last
 
 
 def ssm_layer(p, hidden, cfg) -> jnp.ndarray:
-    """Full Mamba-2 block (train). hidden: [B, S, D]."""
+    """Full Mamba-2 block (train). hidden: [B, S, D]. Name scopes:
+    ``ssd/in_proj``, ``ssd/conv`` (with the dt and A transforms), the scan's
+    ``ssd/chunk`` and ``ssd/state``, and ``ssd/out_proj`` (the D skip, gated
+    RMSNorm and out projection)."""
     B, S, D = hidden.shape
     din, n, h = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
     P = cfg.ssm_head_dim
 
-    zxbcdt = hidden @ p["in_proj"]
-    z, xbc, dt_raw = jnp.split(zxbcdt, [din, din + din + 2 * n], axis=-1)
-    xbc = _causal_conv(xbc, p["conv_w"], p["conv_b"])
-    xbc = jax.nn.silu(xbc)
-    x, B_, C_ = jnp.split(xbc, [din, din + n], axis=-1)
+    with phase("in_proj", layer="ssd"):
+        zxbcdt = hidden @ p["in_proj"]
+        z, xbc, dt_raw = jnp.split(zxbcdt, [din, din + din + 2 * n], axis=-1)
+    with phase("conv", layer="ssd"):
+        xbc = _causal_conv(xbc, p["conv_w"], p["conv_b"])
+        xbc = jax.nn.silu(xbc)
+        x, B_, C_ = jnp.split(xbc, [din, din + n], axis=-1)
 
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])  # [B,S,H]
-    A = -jnp.exp(p["A_log"])  # [H]
-    xh = x.reshape(B, S, h, P)
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])  # [B,S,H]
+        A = -jnp.exp(p["A_log"])  # [H]
+        xh = x.reshape(B, S, h, P)
     y, _ = ssd_scan(xh, dt, A, B_[:, :, None, :], C_[:, :, None, :], cfg.ssm_chunk)
-    y = y + xh.astype(jnp.float32) * p["D"][None, None, :, None]
-    y = y.reshape(B, S, din).astype(hidden.dtype)
+    with phase("out_proj", layer="ssd"):
+        y = y + xh.astype(jnp.float32) * p["D"][None, None, :, None]
+        y = y.reshape(B, S, din).astype(hidden.dtype)
 
-    # gated RMSNorm + out projection
-    gated = y * jax.nn.silu(z)
-    gated = rmsnorm({"scale": p["norm_scale"]}, gated, cfg.norm_eps)
-    return gated @ p["out_proj"]
+        # gated RMSNorm + out projection
+        gated = y * jax.nn.silu(z)
+        gated = rmsnorm({"scale": p["norm_scale"]}, gated, cfg.norm_eps)
+        return gated @ p["out_proj"]
 
 
 # ------------------------------------------------------------------ decode

@@ -34,6 +34,7 @@ from repro.models import attention as attn_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.layers import embed_init, dense_init, init_rmsnorm, init_mlp_block, mlp_block, rmsnorm
+from repro.telemetry import phase
 
 
 # ================================================================== params
@@ -145,7 +146,8 @@ def forward_hidden(
     prefill unembeds just the last position, which keeps the [B, S, V] fp32
     logits tensor (e.g. 67 GB/device for gemma-7b prefill_32k) from ever
     existing."""
-    h = embed_tokens(params, cfg, tokens)
+    with phase("embed", layer="model"):
+        h = embed_tokens(params, cfg, tokens)
     n_prefix = 0
     if prefix_embeds is not None:
         n_prefix = prefix_embeds.shape[1]
@@ -195,18 +197,20 @@ def forward(
 def loss_fn(params, cfg, batch) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Next-token cross-entropy. batch: dict with "tokens", "labels",
     optional "prefix_embeds". labels use -100 as the ignore index."""
-    logits, aux = forward(
+    h, aux = forward_hidden(
         params, cfg, batch["tokens"], prefix_embeds=batch.get("prefix_embeds")
     )
-    labels = batch["labels"]
-    if cfg.n_codebooks:
-        # logits [B,S,K,V], labels [B,K,S]
-        labels = jnp.moveaxis(labels, 1, 2)  # [B,S,K]
-    valid = labels != -100
-    labels_c = jnp.clip(labels, 0)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, labels_c[..., None], axis=-1)[..., 0]
-    loss = jnp.sum(nll * valid) / jnp.maximum(jnp.sum(valid), 1)
+    with phase("unembed", layer="model"):
+        logits = unembed(params, cfg, h)
+        labels = batch["labels"]
+        if cfg.n_codebooks:
+            # logits [B,S,K,V], labels [B,K,S]
+            labels = jnp.moveaxis(labels, 1, 2)  # [B,S,K]
+        valid = labels != -100
+        labels_c = jnp.clip(labels, 0)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, labels_c[..., None], axis=-1)[..., 0]
+        loss = jnp.sum(nll * valid) / jnp.maximum(jnp.sum(valid), 1)
     if aux:
         loss = loss + cfg.router_aux_coef * aux["moe_lb_loss"] + cfg.router_z_coef * aux["moe_z_loss"]
     aux = dict(aux)

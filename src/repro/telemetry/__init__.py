@@ -18,9 +18,11 @@ statistics drift across rounds. This package makes them first-class:
                 drained asynchronously host-side.
   probes.py     the probe math shared by the stacked and packed engines
                 (trim masks, per-bucket dispersion, worker deviation).
-  profiling.py  ``phase()`` markers (jax.named_scope + TraceAnnotation) on
-                pack -> gram -> mix -> kernel -> unpack, and the one-call
-                ``trace_capture`` jax.profiler helper.
+  profiling.py  ``phase()`` name scopes (``jax.named_scope`` only: HLO
+                metadata, no host span) on the sync's pack -> gram -> mix ->
+                kernel -> unpack, the train step's and the model's phases
+                (``SCOPES``), read from a profiler's device trace; and the
+                one-call ``trace_capture`` jax.profiler helper.
   events.py     host-side JSONL structured event log + ring-buffered step
                 timing.
 

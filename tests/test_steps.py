@@ -17,6 +17,7 @@ from repro.distributed.steps import input_specs, make_serve_step, make_train_ste
 from repro.launch.mesh import make_host_mesh, n_workers
 from repro.models import transformer as tfm
 from repro.optim import make_optimizer
+from repro.telemetry.profiling import SCOPES, SYNC_PHASES
 
 
 @pytest.fixture(scope="module")
@@ -205,3 +206,59 @@ def test_prefill_last_only_shapes(mesh):
         np.testing.assert_allclose(np.asarray(logits[:, 0]),
                                    np.asarray(logits_full[:, -1]),
                                    rtol=2e-3, atol=2e-3)
+
+
+# ------------------------------------------------------------- name scopes
+@pytest.fixture(scope="module")
+def step_op_names(mesh):
+    """op_name metadata of the robust train step compiled at smoke widths,
+    per rule: RFA takes the Gram route, CM the coordinatewise one. The one
+    worker of this mesh leaves CM's median a copy that XLA folds away, so
+    the CM sync is also compiled alone, over 4 workers."""
+    import re
+
+    from repro.core.aragg import RobustAggregator
+    from repro.distributed.packing import packed_robust_sync
+
+    def op_names(compiled):
+        return re.findall(r'op_name="([^"]*)"', compiled.as_text())
+
+    cfg = smoke_config("mamba2-130m")
+    batch = {k: jax.ShapeDtypeStruct((1, 32), jnp.int32) for k in ("tokens", "labels")}
+    out = {}
+    for rule in ("rfa", "cm"):
+        byz = ByzConfig(aggregator=rule, mixing="bucketing", s=2, worker_momentum=0.9)
+        step_fn, sh = make_train_step(cfg, byz, mesh, optimizer="adamw")
+        out[rule] = op_names(jax.jit(step_fn).lower(
+            sh["params_shape"], sh["opt_shape"], sh["wm_shape"],
+            jax.random.PRNGKey(0), batch).compile())
+    agg = RobustAggregator.from_spec("cm", mixing="bucketing", s=2)
+    tree = {"a": jax.ShapeDtypeStruct((4, 3, 40), jnp.float32)}
+    out["cm_sync"] = op_names(jax.jit(lambda g, k: packed_robust_sync(g, agg, key=k)[0])
+                              .lower(tree, jax.random.PRNGKey(0)).compile())
+    return out
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_train_step_carries_scope(step_op_names, scope):
+    """Every scope the program declares reaches the compiled step's
+    metadata (the sync's ``mix`` and ``kernel`` only on the CM route)."""
+    names = [n for names in step_op_names.values() for n in names]
+    assert any(scope in n for n in names), scope
+
+
+def test_train_step_forward_and_backward_told_apart(step_op_names):
+    grad = [n for n in step_op_names["rfa"] if "/step/grad/" in n]
+    assert any("transpose(" in n for n in grad)
+    assert any("transpose(" not in n for n in grad)
+    assert any("transpose(jvp(model/unembed))" in n for n in grad)
+
+
+@pytest.mark.parametrize("rule", ["rfa", "cm"])
+def test_sync_phases_only_inside_step_sync(step_op_names, rule):
+    """``telemetry/`` names the sync's phases and nothing else, so the
+    readers that select by it see the sync alone; the phase names stay."""
+    assert SYNC_PHASES == ("pack", "mix", "gram", "coeff", "kernel", "combine", "unpack")
+    tele = [n for n in step_op_names[rule] if "telemetry/" in n]
+    assert tele and all("step/sync/telemetry/" in n for n in tele)
+    assert not [s for s in SCOPES if "telemetry/" in s and not s.startswith("telemetry/")]

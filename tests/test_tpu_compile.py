@@ -73,3 +73,30 @@ def test_sync_kernel_compiles_for_v5e(one_chip, name, W):
     kernel, args, kwargs = KERNELS[name](W, one_chip)
     compiled = kernel.lower(*args, interpret=False, **kwargs).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the kernels each rule's packed sync runs, by the names the benchmark's
+# trace reduction finds them under (bench/reduce.py `named`)
+SYNC_KERNELS = {"rfa": ("pairwise_gram", "bucket_mix"),
+                "cm": ("bucket_mix", "cwise_median")}
+
+
+@pytest.mark.parametrize("rule", sorted(SYNC_KERNELS))
+def test_n25_sync_kernels_keep_their_names(one_chip, monkeypatch, rule):
+    """The W=25 packed sync compiled for a v5e holds an instruction named
+    after each Pallas kernel its rule uses (the kernel's own ``name``)."""
+    from bench import reduce
+    from repro.core.aragg import RobustAggregator
+    from repro.distributed.packing import packed_robust_sync
+    from repro.kernels import ops
+
+    # the kernels ask the backend, which is the CPU here, for interpret mode
+    monkeypatch.setattr(ops, "_interp", lambda interpret=None: bool(interpret))
+    agg = RobustAggregator.from_spec(rule, mixing="bucketing", s=2)
+    tree = {"a": _f32((25, 3, 1000), one_chip), "b": _f32((25, 777), one_chip)}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    text = jax.jit(lambda g, k: packed_robust_sync(g, agg, key=k)[0]).lower(
+        tree, key).compile().as_text()
+    (instrs,) = reduce.scopes_from_hlo([text]).values()
+    for kernel in SYNC_KERNELS[rule]:
+        assert any(reduce.named(kernel)([0, i, 0, 0, "", ""]) for i in instrs), kernel
