@@ -12,15 +12,41 @@ NNM-style pre-aggregation (Allouah et al., *Fixing by Mixing*, 2023), which
 is just another row-stochastic mixing operator.
 
 ``GradPacker`` owns the layout: treedef, per-leaf shapes/dtypes, and column
-offsets, computed once per tree structure and cached (``packer_for``). Each
-leaf's segment is padded up to a ``block_d`` multiple. That per-leaf
-alignment is what makes the packed engine BIT-IDENTICAL to the per-leaf
-oracle: the Gram kernel (kernels/pairwise_gram.py) accumulates fixed
-``[W, block_d]`` block dots in column order, so one call over the packed
-buffer performs the exact same sequence of fp32 operations as the oracle's
-chain of per-leaf calls (seeded via the kernel's ``acc`` input). Mixing and
-combine reduce over the (tiny, zero-padded) worker axis per column, which
-is insensitive to column blocking. Asserted in tests/test_packing.py.
+offsets, computed once per tree structure and cached (``packer_for``).
+
+LAYOUT. A leaf of shape ``[*lead, C]`` is laid out in its segment as its
+``[R, C']`` view, ``R = prod(lead)``: row-major, with the minor dim C
+rounded up to ``C' = lane_width(C)``, a multiple of 128 lanes, and zeros in
+the extra lanes. Every row of such a leaf then starts on a lane-tile
+boundary, so flattening it moves whole 128-lane tiles to whole tiles: one
+transposing copy on a TPU, where an unaligned C (``in_proj``'s 3352) made
+XLA relay the leaf through a flat 1-D copy and a loop over the worker rows.
+A minor dim narrower than one lane tile (a conv kernel's 4, a per-head 24)
+keeps its dense flattening: padding it would multiply the leaf by up to
+128/C, and such leaves are small. Each segment is then padded up to a
+``block_d`` multiple. That per-leaf alignment is what makes the packed
+engine BIT-IDENTICAL to the per-leaf oracle, which flattens each leaf by
+the same ``to_lanes`` view: the Gram kernel (kernels/pairwise_gram.py)
+accumulates fixed ``[W, block_d]`` block dots in column order, so one call
+over the packed buffer performs the exact same sequence of fp32 operations
+as the oracle's chain of per-leaf calls (seeded via the kernel's ``acc``
+input). Mixing and combine reduce over the (tiny, zero-padded) worker axis
+per column, which is insensitive to column blocking. Asserted in
+tests/test_packing.py. ``unpack*`` drop the extra lanes before restoring
+each leaf's shape.
+
+ROWS. Where the kernels are called directly (a trivial mesh), ``pack``
+writes ``max(8, round_up(W, 8))`` rows, the sublane multiple the kernels'
+blocks need, so the buffer is born in the shape ``pairwise_gram`` and
+``bucket_mix`` read and their wrappers neither pad nor copy it. The extra
+rows are zero and reach only those two kernels, which contract over the
+worker axis: the sync slices the Gram back to ``[W, W]`` and gives the
+padded rows zero columns of the mixing matrix and zero combine weights.
+They never reach the order-statistic kernels (``cwise_median``,
+``cwise_trimmed_mean``), whose padding sentinel is ``+inf``, not 0: those
+read only the mixed ``[m, n_pad]`` rows. On a multi-device mesh ``pack``
+keeps W rows: ``reshard_in``'s all-to-all carries the messages and nothing
+else, and each device's kernel pads its own local block.
 
 COLLECTIVE SCHEDULE: ``reshard_in`` lays the packed parameter dimension
 across ALL mesh axes with the worker axis replicated (one all-to-all);
@@ -70,77 +96,138 @@ import jax.numpy as jnp
 
 from repro.core.aragg import RobustAggregator
 from repro.distributed import shard_kernels
-from repro.kernels import ops
+from repro.kernels import ops, pack_rows
 from repro.telemetry import InflightMetrics, phase
 from repro.telemetry import probes as _probes
+
+
+LANE = 128  # a TPU vreg's lane count: the minor tile of every fp32 layout
 
 
 def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
 
 
+def kernel_rows(W: int) -> int:
+    """Rows of a buffer the kernels read without padding it: W rounded up
+    to the 8-row sublane multiple."""
+    return max(8, _round_up(W, 8))
+
+
+def lane_width(c: int) -> int:
+    """Columns a leaf row of minor dim ``c`` takes in the packed buffer:
+    ``c`` rounded up to whole lane tiles, or ``c`` itself when it is
+    narrower than one tile (module docstring, LAYOUT)."""
+    return _round_up(c, LANE) if c > LANE else c
+
+
+def to_lanes(x: jnp.ndarray, rows: Optional[int] = None) -> jnp.ndarray:
+    """Stacked leaf ``[W, *shape]`` -> its ``[rows, R * C']`` view: minor
+    dim zero-padded to ``lane_width``, worker rows zero-padded to ``rows``
+    (default W). Keeps the dtype."""
+    W = x.shape[0]
+    c = x.shape[-1] if x.ndim > 1 else 1
+    x = x.reshape(W, -1, c)
+    rows = W if rows is None else rows
+    if rows != W or lane_width(c) != c:
+        x = jnp.pad(x, ((0, rows - W), (0, 0), (0, lane_width(c) - c)))
+    return x.reshape(rows, -1)
+
+
+def from_lanes(y: jnp.ndarray, shape: tuple) -> jnp.ndarray:
+    """Inverse of ``to_lanes`` on the trailing axis: ``[..., R * C']`` ->
+    ``[..., *shape]``, extra lanes dropped."""
+    c = shape[-1] if shape else 1
+    lead = y.shape[:-1]
+    if lane_width(c) != c:
+        y = y.reshape(lead + (-1, lane_width(c)))[..., :c]
+    return y.reshape(lead + tuple(shape))
+
+
 class GradPacker:
     """Flattens a per-worker gradient pytree (leaves ``[W, ...]``) into one
-    padded ``[W, n_pad]`` fp32 buffer and back. Layout is static per tree
-    structure; build instances via ``packer_for`` to get caching."""
+    padded ``[rows, n_pad]`` fp32 buffer of lane-aligned, ``block_d``-
+    aligned leaf segments, and back (module docstring, LAYOUT and ROWS).
+    Layout is static per tree structure; build instances via
+    ``packer_for`` to get caching."""
 
     def __init__(self, treedef, leaf_shapes: Tuple[tuple, ...],
                  leaf_dtypes: tuple, block_d: int = 2048):
-        if block_d % 128:
-            raise ValueError(f"block_d must be a multiple of 128, got {block_d}")
+        if block_d % LANE:
+            raise ValueError(f"block_d must be a multiple of {LANE}, got {block_d}")
         self.treedef = treedef
         self.leaf_shapes = tuple(tuple(s) for s in leaf_shapes)  # sans worker axis
         self.leaf_dtypes = tuple(jnp.dtype(d) for d in leaf_dtypes)
         self.block_d = int(block_d)
         self.sizes = tuple(math.prod(s) for s in self.leaf_shapes)
-        # each leaf segment is padded to a block_d multiple so kernel blocks
-        # never straddle leaves (the bit-exactness alignment, module docstring)
-        self.padded = tuple(_round_up(z, block_d) if z else 0 for z in self.sizes)
+        # columns of each leaf's lane-aligned [R, C'] view
+        self.spans = tuple(
+            z // s[-1] * lane_width(s[-1]) if z and s else z
+            for z, s in zip(self.sizes, self.leaf_shapes))
+        # each segment is padded to a block_d multiple so kernel blocks
+        # never straddle leaves (the bit-exactness alignment)
+        self.padded = tuple(_round_up(z, block_d) for z in self.spans)
         self.offsets = tuple(
             sum(self.padded[:i]) for i in range(len(self.padded))
         )
         self.n_params = sum(self.sizes)
+        self.lane_pad_cols = sum(self.spans) - self.n_params
         self.n_pad = sum(self.padded)
 
     # ------------------------------------------------------------------ pack
-    def pack(self, grads_w: Any) -> jnp.ndarray:
-        """Stacked tree (leaves ``[W, ...]``) -> packed ``[W, n_pad]`` fp32.
+    def pack(self, grads_w: Any, for_kernels: bool = False) -> jnp.ndarray:
+        """Stacked tree (leaves ``[W, ...]``) -> packed ``[rows, n_pad]`` fp32.
 
-        Writes each segment into a zeros buffer with dynamic_update_slice —
-        under jit XLA aliases the updates in place, so pack costs one pass
-        over the gradient bytes. (A concatenate of interleaved data/zero
-        pieces is 20x slower on CPU XLA at transformer leaf counts.)"""
+        ``for_kernels`` says the sync kernels will read the buffer directly
+        (a trivial mesh): it then has ``kernel_rows(W)`` rows, and the
+        ``pack_rows`` kernel writes each leaf of whole-tile rows in one pass
+        (module docstring, ROWS). Otherwise it has W rows. Every other leaf
+        is written as one padded piece by XLA. Each segment is written once,
+        padding included: there is no zero-filled buffer to update."""
         leaves = jax.tree_util.tree_leaves(grads_w)
         W = leaves[0].shape[0]
-        buf = jnp.zeros((W, self.n_pad), jnp.float32)
-        for leaf, size, off in zip(leaves, self.sizes, self.offsets):
+        rows = kernel_rows(W) if for_kernels else W
+        buf, pieces = None, []
+        for leaf, shape, size, span, seg, off in zip(
+                leaves, self.leaf_shapes, self.sizes, self.spans, self.padded,
+                self.offsets):
             if size == 0:
                 continue
-            piece = leaf.reshape(W, size).astype(jnp.float32)
+            x = leaf.astype(jnp.float32)
+            C = shape[-1] if shape else 1
+            if for_kernels and pack_rows.supports(size // C, C):
+                buf = pack_rows.pack_rows(
+                    x.reshape(W, -1, C), (rows, self.n_pad) if buf is None else buf,
+                    off=off, seg=seg)
+            else:
+                x = to_lanes(x, rows)
+                pieces.append((off, x if seg == span else
+                               jnp.pad(x, ((0, 0), (0, seg - span)))))
+        if buf is None:
+            return (jnp.concatenate([p for _, p in pieces], axis=1) if pieces
+                    else jnp.zeros((rows, self.n_pad), jnp.float32))
+        for off, piece in pieces:
             buf = jax.lax.dynamic_update_slice(buf, piece, (0, off))
         return buf
 
     # ---------------------------------------------------------------- unpack
-    def unpack(self, vec: jnp.ndarray) -> Any:
-        """Packed row ``[n_pad]`` -> gradient tree (original shapes/dtypes)."""
+    def _leaves(self, cols) -> Any:
+        """``cols(offset, span)`` -> that segment's ``[..., span]`` columns;
+        returns the tree of leaves in their shapes and dtypes."""
         leaves = [
-            vec[off : off + size].reshape(shape).astype(dtype)
-            for off, size, shape, dtype in zip(
-                self.offsets, self.sizes, self.leaf_shapes, self.leaf_dtypes
-            )
+            from_lanes(cols(off, span), shape).astype(dtype)
+            for off, span, shape, dtype in zip(
+                self.offsets, self.spans, self.leaf_shapes, self.leaf_dtypes)
         ]
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
+    def unpack(self, vec: jnp.ndarray) -> Any:
+        """Packed row ``[n_pad]`` -> gradient tree (original shapes/dtypes)."""
+        return self._leaves(lambda off, span: vec[off:off + span])
+
     def unpack_stacked(self, buf: jnp.ndarray) -> Any:
         """Packed stack ``[k, n_pad]`` -> tree with the leading axis kept."""
-        k = buf.shape[0]
-        leaves = [
-            buf[:, off : off + size].reshape((k,) + shape).astype(dtype)
-            for off, size, shape, dtype in zip(
-                self.offsets, self.sizes, self.leaf_shapes, self.leaf_dtypes
-            )
-        ]
-        return jax.tree_util.tree_unflatten(self.treedef, leaves)
+        return self._leaves(lambda off, span: buf[:, off:off + span])
 
     def __repr__(self) -> str:  # pragma: no cover
         return (f"GradPacker(n_leaves={len(self.sizes)}, n_params={self.n_params}, "
@@ -207,9 +294,9 @@ def unpack_to_shardings(packer: GradPacker, vec: jnp.ndarray,
             f"{len(packer.sizes)}-leaf layout")
     leaves = [
         jax.lax.with_sharding_constraint(
-            vec[off:off + size].reshape(shape).astype(dtype), sh)
-        for off, size, shape, dtype, sh in zip(
-            packer.offsets, packer.sizes, packer.leaf_shapes,
+            from_lanes(vec[off:off + span], shape).astype(dtype), sh)
+        for off, span, shape, dtype, sh in zip(
+            packer.offsets, packer.spans, packer.leaf_shapes,
             packer.leaf_dtypes, shardings)
     ]
     return jax.tree_util.tree_unflatten(packer.treedef, leaves)
@@ -217,6 +304,13 @@ def unpack_to_shardings(packer: GradPacker, vec: jnp.ndarray,
 
 def _mesh_is_trivial(mesh) -> bool:
     return mesh is None or mesh.devices.size == 1
+
+
+def _widen(op: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """A ``[k, W]`` operator over the worker axis, zero-padded to ``[k, rows]``
+    so that the buffer's padded rows take no weight."""
+    W = op.shape[-1]
+    return op if rows == W else jnp.pad(op, ((0, 0), (0, rows - W)))
 
 
 # ------------------------------------------------------------------- engine
@@ -269,12 +363,17 @@ def _packed_robust_sync(grads_w, aggregator, key, mesh, block_d,
     if use_kernels is None:
         use_kernels = True
     sharded = use_kernels and not _mesh_is_trivial(mesh)
+    # rows padded at birth where the kernels run unsharded (module
+    # docstring, ROWS); the worker axis of every operator is widened to match
+    direct = use_kernels and not sharded
+    rows = kernel_rows(W) if direct else W
     info: dict = {}
     tm = InflightMetrics(telemetry)
     if tm:
         tm.put("sync_n_workers", W)
         tm.put("sync_n_params", packer.n_params)
         tm.put("sync_n_pad", packer.n_pad)
+        tm.put("sync_lane_pad_cols", packer.lane_pad_cols)
         tm.put("sync_ingress_bytes", W * packer.n_pad * 4)
         tm.put("sync_egress_bytes",
                packer.n_params * 4
@@ -293,11 +392,11 @@ def _packed_robust_sync(grads_w, aggregator, key, mesh, block_d,
         return egress(out), info
 
     with phase("pack"):
-        buf = reshard_in(packer.pack(grads_w), mesh)  # [W, n_pad] fp32
+        buf = reshard_in(packer.pack(grads_w, for_kernels=direct), mesh)
 
     if aggregator.base.coordinatewise:
         mix_key = None if key is None else jax.random.split(key)[0]
-        m = aggregator.mixer.matrix(mix_key, W)
+        m = _widen(aggregator.mixer.matrix(mix_key, W), rows)
         with phase("mix"):
             if not use_kernels:
                 mixed = m @ buf
@@ -365,7 +464,7 @@ def _packed_robust_sync(grads_w, aggregator, key, mesh, block_d,
         elif sharded:
             gram = shard_kernels.gram(buf, mesh, block_d=block_d)
         else:
-            gram = ops.gram(buf, block_d=block_d)
+            gram = ops.gram(buf, block_d=block_d)[:W, :W]
     with phase("coeff"):
         if tm:
             weights, stats = aggregator.worker_weights_and_stats_from_gram(
@@ -382,7 +481,8 @@ def _packed_robust_sync(grads_w, aggregator, key, mesh, block_d,
             out = shard_kernels.mix_apply(weights[None, :], buf, mesh,
                                           block_d=block_d)[0]
         else:
-            out = ops.mix_apply(weights[None, :], buf, block_d=block_d)[0]
+            out = ops.mix_apply(_widen(weights[None, :], rows), buf,
+                                block_d=block_d)[0]
     return finish(out)
 
 

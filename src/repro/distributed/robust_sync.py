@@ -45,8 +45,10 @@ total, but split into TWO collectives and several kernel launches PER LEAF
 per step (stats + combine) — hundreds of small all-to-alls per round on a
 transformer, which is what the packed engine eliminates. With
 ``use_kernels=True`` its Gram phase chains through the same Pallas kernel
-blocks as the packed engine (``acc`` + ``full_blocks``), making the two
-engines bit-identical (asserted in tests/test_packing.py); with the default
+blocks as the packed engine (``acc`` + ``full_blocks``) on each leaf's
+lane-aligned ``[W, R * C']`` view (``packing.to_lanes``, the layout of the
+leaf's segment in the packed buffer), making the two engines
+bit-identical (asserted in tests/test_packing.py); with the default
 ``use_kernels=False`` it is the pure-jnp GSPMD path.
 
 Semantics are equal to ``RobustAggregator(...)`` on the stacked vector
@@ -99,7 +101,7 @@ def tree_gram(grads_w: Any, n_workers: int, mesh=None, use_kernels: bool = False
     for leaf in jax.tree_util.tree_leaves(grads_w):
         if leaf.size == 0:
             continue
-        flat = _colshard(leaf.reshape(n_workers, -1), mesh)
+        flat = _colshard(packing.to_lanes(leaf), mesh)
         if use_kernels:
             gram = ops.gram(flat, acc=gram, block_d=block_d, full_blocks=True)
         else:
@@ -114,12 +116,12 @@ def tree_combine(grads_w: Any, weights: jnp.ndarray, mesh=None,
     def one(leaf):
         if leaf.size == 0:  # guard BEFORE reshape(W, -1) / reshard
             return jnp.zeros(leaf.shape[1:], leaf.dtype)
-        flat = _colshard(leaf.reshape(leaf.shape[0], -1), mesh)
+        flat = _colshard(packing.to_lanes(leaf), mesh)
         if use_kernels:
             out = ops.mix_apply(weights[None, :], flat, block_d=block_d)[0]
         else:
             out = weights @ _leaf32(flat)
-        return out.reshape(leaf.shape[1:]).astype(leaf.dtype)
+        return packing.from_lanes(out, leaf.shape[1:]).astype(leaf.dtype)
 
     return jax.tree_util.tree_map(one, grads_w)
 
@@ -130,12 +132,12 @@ def tree_mix(grads_w: Any, mix_matrix: jnp.ndarray, mesh=None,
     def one(leaf):
         if leaf.size == 0:  # guard BEFORE reshape(W, -1) / reshard
             return jnp.zeros((mix_matrix.shape[0],) + leaf.shape[1:], leaf.dtype)
-        flat = _colshard(leaf.reshape(leaf.shape[0], -1), mesh)
+        flat = _colshard(packing.to_lanes(leaf), mesh)
         if use_kernels:
             out = ops.mix_apply(mix_matrix, flat, block_d=block_d)
         else:
             out = mix_matrix @ _leaf32(flat)
-        return out.reshape((mix_matrix.shape[0],) + leaf.shape[1:]).astype(leaf.dtype)
+        return packing.from_lanes(out, leaf.shape[1:]).astype(leaf.dtype)
 
     return jax.tree_util.tree_map(one, grads_w)
 
@@ -174,7 +176,7 @@ def _per_leaf_sync(
         def one(leaf):
             if leaf.size == 0:  # guard BEFORE reshape(W, -1) / reshard
                 return jnp.zeros(leaf.shape[1:], leaf.dtype)
-            flat = _colshard(leaf.reshape(n_workers, -1), mesh)
+            flat = _colshard(packing.to_lanes(leaf), mesh)
             mixed = ops.mix_apply(m, flat, block_d=block_d)
             if aggregator.base.name == "cm":
                 out = ops.cm_aggregate(mixed, block_d=block_d)
@@ -183,7 +185,7 @@ def _per_leaf_sync(
                 out = ops.tm_aggregate(mixed, b, block_d=block_d)
             else:
                 out = aggregator.base.combine_leaf(mixed)
-            return out.reshape(leaf.shape[1:]).astype(leaf.dtype)
+            return packing.from_lanes(out, leaf.shape[1:]).astype(leaf.dtype)
 
         return jax.tree_util.tree_map(one, grads_w), info
 

@@ -28,7 +28,10 @@ def _mix_kernel(m_ref, x_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def bucket_mix(mix: jnp.ndarray, xs: jnp.ndarray, *, block_d: int = 2048,
                interpret: bool | None = None):
-    """mix: [m, W] row-stochastic; xs: [W, d] -> mixed [m, d] fp32."""
+    """mix: [m, W] row-stochastic; xs: [W, d] -> mixed [m, d] fp32.
+
+    Pads W to a multiple of 8 (sublane) and d to a multiple of the block;
+    an ``xs`` already of the padded shape is read as it is, with no copy."""
     from repro.kernels.ops import _interp  # ops imports this module
 
     interpret = _interp(interpret)
@@ -41,7 +44,8 @@ def bucket_mix(mix: jnp.ndarray, xs: jnp.ndarray, *, block_d: int = 2048,
     bd = -(-bd // 128) * 128
     dp = -(-d // bd) * bd
     mx = jnp.zeros((mp, Wp), jnp.float32).at[:m, :W].set(mix.astype(jnp.float32))
-    x = jnp.zeros((Wp, dp), xs.dtype).at[:W, :d].set(xs)
+    x = xs if (W, d) == (Wp, dp) else (
+        jnp.zeros((Wp, dp), xs.dtype).at[:W, :d].set(xs))
 
     out = pl.pallas_call(
         _mix_kernel,

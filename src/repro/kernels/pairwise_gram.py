@@ -52,8 +52,10 @@ def pairwise_gram(xs: jnp.ndarray, acc: jnp.ndarray | None = None, *,
 
     Pads W to a multiple of 8 (sublane) and d to a multiple of the block
     (lane=128-aligned); zero padding contributes 0 to every inner product.
-    ``full_blocks`` forces the block width to exactly ``block_d`` (padding d
-    up to a ``block_d`` multiple) so block shapes are independent of ``d``.
+    An ``xs`` already of the padded shape (the packed sync's buffer) is
+    read as it is, with no copy. ``full_blocks`` forces the block width to
+    exactly ``block_d`` (padding d up to a ``block_d`` multiple) so block
+    shapes are independent of ``d``.
     """
     from repro.kernels.ops import _interp  # ops imports this module
 
@@ -66,7 +68,8 @@ def pairwise_gram(xs: jnp.ndarray, acc: jnp.ndarray | None = None, *,
         bd = min(block_d, max(128, -(-d // 128) * 128))
         bd = -(-bd // 128) * 128
     dp = max(bd, -(-d // bd) * bd)
-    x = jnp.zeros((Wp, dp), xs.dtype).at[:W, :d].set(xs)
+    x = xs if (W, d) == (Wp, dp) else (
+        jnp.zeros((Wp, dp), xs.dtype).at[:W, :d].set(xs))
     a = jnp.zeros((Wp, Wp), jnp.float32)
     if acc is not None:
         a = a.at[:W, :W].set(acc.astype(jnp.float32))

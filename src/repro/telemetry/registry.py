@@ -114,6 +114,8 @@ register("bucket_dispersion", "aggregate", "per_bucket",
 register("sync_n_workers", "sync", "counter", "worker rows W entering the sync")
 register("sync_n_params", "sync", "counter", "true parameter count")
 register("sync_n_pad", "sync", "counter", "padded packed-buffer columns")
+register("sync_lane_pad_cols", "sync", "counter",
+         "zero columns added by lane-aligning leaf rows (of n_pad)")
 register("sync_ingress_bytes", "sync", "counter",
          "packed-buffer ingress payload W * n_pad * 4")
 register("sync_egress_bytes", "sync", "counter",

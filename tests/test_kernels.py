@@ -110,6 +110,32 @@ def test_block_size_invariance(block_d):
     )
 
 
+# (W, R, C, seg): two steps over a partial last row block; leaf rows fewer
+# than 8 with a step of zeros only; two worker groups, the second partial
+PACK_CASES = [(1, 9, 128, 1280), (12, 6, 768, 6144), (25, 20, 200, 6144)]
+
+
+@pytest.mark.parametrize("W,R,C,seg", PACK_CASES)
+def test_pack_rows_writes_lane_aligned_rows(W, R, C, seg):
+    """``pack_rows`` writes the plain layout: each worker's rows, zero-padded
+    from C to whole lane tiles, one after another; zero rows up to the
+    8-row multiple; zeros up to ``seg``. The rest of an aliased buffer is
+    left as it was."""
+    from repro.kernels.pack_rows import pack_rows
+
+    x = _xs((W, R, C), jnp.float32, seed=R)
+    Wp, Cp = max(8, -(-W // 8) * 8), -(-C // 128) * 128
+    want = np.zeros((Wp, seg), np.float32)
+    want[:W, :R * Cp] = np.pad(np.asarray(x), ((0, 0), (0, 0), (0, Cp - C))
+                               ).reshape(W, -1)
+    fresh = pack_rows(x, (Wp, 128 + seg), off=128, seg=seg)
+    np.testing.assert_array_equal(np.asarray(fresh)[:, 128:], want)
+    before = jnp.full((Wp, 128 + seg + 256), 7.0, jnp.float32)
+    after = np.asarray(pack_rows(x, before, off=128, seg=seg))
+    np.testing.assert_array_equal(after[:, 128:128 + seg], want)
+    assert (after[:, :128] == 7.0).all() and (after[:, 128 + seg:] == 7.0).all()
+
+
 # --------------------------------------------------- composed aggregator ops
 def test_ops_rfa_aggregate_matches_ref():
     xs = _xs((21, 1500), jnp.float32)

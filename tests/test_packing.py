@@ -56,6 +56,140 @@ def test_pack_unpack_roundtrip_mixed_dtypes(key):
                                       np.asarray(b, np.float32))
 
 
+def _lane_tree(key, W=5):
+    """Leaves whose minor dim is not a multiple of 128: lane-padded rows
+    (200, 130), a dense narrow leaf (24), a bf16 leaf and a whole-tile one."""
+    ks = jax.random.split(key, 5)
+    return {
+        "a": jax.random.normal(ks[0], (W, 3, 8, 200), jnp.float32),
+        "b": jax.random.normal(ks[1], (W, 5, 24), jnp.float32),
+        "c": jax.random.normal(ks[2], (W, 6, 130), jnp.float32).astype(jnp.bfloat16),
+        "d": jax.random.normal(ks[3], (W, 9, 128), jnp.float32),
+        "e": jax.random.normal(ks[4], (W, 300), jnp.float32).astype(jnp.float16),
+    }
+
+
+@pytest.mark.parametrize("for_kernels", [False, True])
+@pytest.mark.parametrize("W", [1, 5, 12])
+def test_lane_aligned_roundtrip(key, W, for_kernels):
+    """Leaves whose minor dim is not a multiple of 128 come back exactly,
+    through either writer of the buffer (XLA pieces, or the pack_rows
+    kernel where the buffer feeds the kernels directly)."""
+    tree = _lane_tree(key, W)
+    packer = packer_for(tree, block_d=BLOCK_D)
+    buf = packer.pack(tree, for_kernels=for_kernels)
+    rows = packing.kernel_rows(W) if for_kernels else W
+    assert buf.shape == (rows, packer.n_pad) and buf.dtype == jnp.float32
+    assert not np.asarray(buf[W:]).any()  # padded rows are zero
+    back = packer.unpack_stacked(buf[:W])
+    for a, b in zip(jax.tree_util.tree_leaves(tree),
+                    jax.tree_util.tree_leaves(back)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    row = packer.unpack(buf[W - 1])
+    for a, b in zip(jax.tree_util.tree_leaves(tree),
+                    jax.tree_util.tree_leaves(row)):
+        np.testing.assert_array_equal(np.asarray(a[W - 1], np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def test_segments_and_lane_rows_are_aligned(key):
+    """Every segment starts on a block_d boundary; every row of a
+    lane-padded leaf starts on a multiple of 128 and is followed by zeros
+    up to its lane width."""
+    W = 3
+    tree = _lane_tree(key, W)
+    packer = packer_for(tree, block_d=BLOCK_D)
+    buf = np.asarray(packer.pack(tree))
+    assert all(off % BLOCK_D == 0 for off in packer.offsets)
+    assert packer.n_pad % BLOCK_D == 0
+    padded = 0
+    for leaf, shape, off in zip(jax.tree_util.tree_leaves(tree),
+                                packer.leaf_shapes, packer.offsets):
+        C, Cp = shape[-1], packing.lane_width(shape[-1])
+        if C == Cp:
+            continue
+        padded += 1
+        assert Cp % 128 == 0
+        rows = np.asarray(leaf, np.float32).reshape(W, -1, C)
+        for r in range(rows.shape[1]):
+            start = off + r * Cp
+            assert start % 128 == 0
+            np.testing.assert_array_equal(buf[:, start:start + C], rows[:, r])
+            assert not buf[:, start + C:start + Cp].any()
+    assert padded == 3  # the 200-, 130- and 300-wide leaves; 24 stays dense
+
+
+def test_lane_pad_cols_counter(key):
+    """``sync_lane_pad_cols`` is the hand count of zero lanes: (256-200)
+    lanes on 24 rows, (256-130) on 6 and (384-300) on the one row of the
+    300-wide leaf; the 24- and 128-wide leaves add none."""
+    tree = _lane_tree(key, W=4)
+    packer = packer_for(tree, block_d=BLOCK_D)
+    hand = 24 * (256 - 200) + 6 * (256 - 130) + (384 - 300)
+    assert packer.lane_pad_cols == hand
+    ra = RobustAggregator.from_spec("rfa", mixing="bucketing", s=2)
+    _, info = robust_gradient_sync(tree, ra, key=key, engine="packed",
+                                   block_d=BLOCK_D, telemetry=True)
+    assert int(info["telemetry"]["sync_lane_pad_cols"]) == hand
+    assert int(info["telemetry"]["sync_n_pad"]) == packer.n_pad
+
+
+@pytest.mark.parametrize("use_kernels,expect", [(None, 8), (False, 5)])
+def test_buffer_rows_on_a_trivial_mesh(key, monkeypatch, use_kernels, expect):
+    """Where the kernels read the buffer directly, it is born with the
+    kernels' 8-row multiple; the plain-jnp route keeps W rows."""
+    seen = []
+    orig = packing.reshard_in
+
+    def record(buf, mesh):
+        seen.append(buf.shape)
+        return orig(buf, mesh)
+
+    monkeypatch.setattr(packing, "reshard_in", record)
+    tree = _f32_tree(key, W=5, sizes=((3, 200), (40,)))
+    ra = RobustAggregator.from_spec("cm", mixing="bucketing", s=2)
+    robust_gradient_sync(tree, ra, key=key, engine="packed", block_d=BLOCK_D,
+                         use_kernels=use_kernels)
+    assert [s[0] for s in seen] == [expect]
+
+
+_MULTI_DEVICE_ROWS = """
+import jax, jax.numpy as jnp
+from repro.core.aragg import RobustAggregator
+from repro.distributed import packing
+from repro.distributed.robust_sync import robust_gradient_sync
+from repro.launch.mesh import make_host_mesh
+seen = []
+orig = packing.reshard_in
+packing.reshard_in = lambda buf, mesh: (seen.append(buf.shape), orig(buf, mesh))[1]
+tree = {"a": jnp.ones((5, 3, 200)), "b": jnp.ones((5, 40))}
+for rule in ("rfa", "cm"):
+    ra = RobustAggregator.from_spec(rule, mixing="bucketing", s=2)
+    jax.make_jaxpr(lambda t: robust_gradient_sync(
+        t, ra, key=jax.random.PRNGKey(0), mesh=make_host_mesh(4, 2),
+        engine="packed", block_d=256)[0])(tree)
+print([s[0] for s in seen])
+"""
+
+
+def test_buffer_rows_on_a_multi_device_mesh():
+    """On a forced 8-device mesh the buffer keeps W rows: the ingress
+    all-to-all carries the messages and no padding rows."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               PYTHONPATH="src" + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    proc = subprocess.run([sys.executable, "-c", _MULTI_DEVICE_ROWS], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.strip().splitlines()[-1] == "[5, 5]"
+
+
 def test_packer_layout_is_cached(key):
     tree = _f32_tree(key)
     assert packer_for(tree, block_d=BLOCK_D) is packer_for(tree, block_d=BLOCK_D)

@@ -100,3 +100,88 @@ def test_n25_sync_kernels_keep_their_names(one_chip, monkeypatch, rule):
     (instrs,) = reduce.scopes_from_hlo([text]).values()
     for kernel in SYNC_KERNELS[rule]:
         assert any(reduce.named(kernel)([0, i, 0, 0, "", ""]) for i in instrs), kernel
+
+
+def _quarter_tree(sharding):
+    """The n=25 messages of the benchmark's sync cells: one [25, ...] fp32
+    leaf per parameter of a quarter of mamba2-130m (6 layers, 12570 rows of
+    the embedding), whose ``in_proj`` rows are 3352 wide, not whole tiles."""
+    import json
+    from pathlib import Path
+
+    from bench.entries.robust_sync import program_config
+    from repro.models import transformer as tfm
+
+    cfg = json.loads((Path(__file__).resolve().parents[1] / "bench" / "configs"
+                      / "n25-mamba2-130m-quarter.json").read_text())
+    shapes = jax.eval_shape(
+        lambda: tfm.init_params(program_config(cfg), jax.random.PRNGKey(0)))
+    return jax.tree_util.tree_map(
+        lambda x: _f32((cfg["workers"],) + x.shape, sharding), shapes)
+
+
+def _ops(text, opcodes):
+    """[(instruction, op_name)] of the HLO instructions with these opcodes."""
+    import re
+
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.*)$", line)
+        if not m:
+            continue
+        head = m.group(2).split(", metadata=")[0]
+        if any(f" {op}(" in head for op in opcodes):
+            scope = re.search(r'op_name="([^"]*)"', line)
+            found.append((m.group(1), scope.group(1) if scope else "", head))
+    return found
+
+
+@pytest.mark.parametrize("rule", sorted(SYNC_KERNELS))
+def test_n25_sync_pack_has_no_relayout_loop(one_chip, monkeypatch, rule):
+    """The packed n=25 sync of the quarter tree compiled for a v5e has no
+    ``while`` outside the coefficient phase (the pack once relaid
+    ``in_proj`` by a loop over the 25 worker rows), and nothing pads or
+    scatters the packed buffer before the gram or mix kernel reads it."""
+    from repro.core.aragg import RobustAggregator
+    from repro.distributed.packing import packer_for, packed_robust_sync
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_interp", lambda interpret=None: bool(interpret))
+    agg = RobustAggregator.from_spec(rule, mixing="bucketing", s=2)
+    tree = _quarter_tree(one_chip)
+    n_pad = packer_for(tree).n_pad
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    text = jax.jit(lambda g, k: packed_robust_sync(g, agg, key=k)[0]).lower(
+        tree, key).compile().as_text()
+    loops = [op for op in _ops(text, ("while",))
+             if "telemetry/coeff" not in op[1]]
+    assert not loops, loops
+    pads = [op for op in _ops(text, ("pad", "scatter"))
+            if ("telemetry/gram" in op[1] or "telemetry/mix" in op[1])
+            and f",{n_pad}]" in op[2]]
+    assert not pads, pads
+    assert "pack_rows" in text
+
+
+@pytest.mark.parametrize("R,C", [(768, 3352), (64, 8192)],
+                         ids=["in_proj", "widest"])
+@pytest.mark.parametrize("W", [1, 25, 53])
+def test_pack_rows_compiles_for_v5e(one_chip, W, R, C):
+    """The pack kernel at ``in_proj``'s rows (3352 wide, lane-padded to
+    3456) of one mamba2-130m layer and at the widest rows it takes, on a
+    fresh and on an aliased buffer."""
+    from repro.distributed.packing import kernel_rows, lane_width
+    from repro.kernels.pack_rows import pack_rows, supports
+
+    assert supports(R, C)
+    assert C < 8192 or not supports(R, C + 1)  # the widest rows taken
+    seg = R * lane_width(C)
+    shape = (kernel_rows(W), 2 * seg)
+
+    def two_leaves(x, y):
+        buf = pack_rows(x, shape, off=0, seg=seg, interpret=False)
+        return pack_rows(y, buf, off=seg, seg=seg, interpret=False)
+
+    x = _f32((W, R, C), one_chip)
+    text = jax.jit(two_leaves).lower(x, x).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
